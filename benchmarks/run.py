@@ -21,6 +21,8 @@ def main() -> None:
     ap.add_argument("--json", default="",
                     help="write results to this path (BENCH_*.json)")
     args = ap.parse_args()
+    from repro.envutil import enable_compile_cache
+    enable_compile_cache()
 
     from . import (bench_algorithms, bench_data_scaling, bench_ipc,
                    bench_kernels, bench_machine_scaling, bench_serving,

@@ -13,10 +13,7 @@ import jax as _jax
 # jax op initializes the backend (same contract as launch/dryrun.py's
 # XLA_FLAGS lines). Everything hot runs under jit, where the loss of
 # eager dispatch/compute overlap is unobservable.
-try:
-    _jax.config.update("jax_cpu_enable_async_dispatch", False)
-except Exception:  # older/newer jax without the option: keep going
-    pass
+_jax.config.update("jax_cpu_enable_async_dispatch", False)
 
 from .core.api import UniGPS  # noqa: F401
 from .core.graph import PropertyGraph, from_edges, partition_graph  # noqa: F401

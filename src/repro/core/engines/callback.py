@@ -22,6 +22,7 @@ where the UDFs are traced into XLA and the boundary disappears entirely.
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -61,6 +62,18 @@ def _no_tracer(tree, what: str):
                 f"repro.lint` on the program (docs/linting.md#ul203).")
 
 
+def _on_host_cpu(fn):
+    """Run a host callback's eager jax ops on the host CPU device. The
+    enclosing executable holds the accelerator while it waits on the
+    callback, so eager ops dispatched to the default (accelerator)
+    device from inside it would wait on it in turn."""
+    @functools.wraps(fn)
+    def wrapped(*args):
+        with jax.default_device(jax.devices("cpu")[0]):
+            return fn(*args)
+    return wrapped
+
+
 def _host_program(program, lane_vals):
     """Rebind the concrete lane values delivered to the host callback."""
     if lane_vals:
@@ -83,6 +96,7 @@ class CallbackEngine:
         # and be rebound host-side, never captured
         lanes = _lane_operands(program)
 
+        @_on_host_cpu
         def host(vp, ib, mask, it_, *lane_vals):
             prog = _host_program(program, lane_vals)
             new_props, is_active = jax.vmap(
@@ -111,6 +125,7 @@ class CallbackEngine:
 
         lanes = _lane_operands(program)
 
+        @_on_host_cpu
         def host(vp, act, lo, *lane_vals):
             prog = _host_program(program, lane_vals)
             lo = jax.tree.map(jnp.asarray, lo)

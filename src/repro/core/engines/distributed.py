@@ -39,7 +39,7 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .. import message_plane, records, vcprog
 from ..graph import PropertyGraph, partition_graph
@@ -1160,26 +1160,22 @@ def run_vcprog_distributed(program: vcprog.VCProgram, graph: PropertyGraph,
     resilient = (bool(checkpoint_dir) or int(checkpoint_every or 0) > 0
                  or guards_on or bool(fault_specs))
 
+    # every [P, ...] array goes host -> its part's device directly, so no
+    # device ever holds another part's share (nor the whole graph)
+    put = _part_placer(mesh)
     # initial vertex props: the input props (init_vertex runs on device)
-    vprops0 = jax.tree.map(jnp.asarray, sg["vprops_in"])
-    active0 = jnp.ones((Pn, v_pp), bool)
-    edges = {
-        "edge_src_local": jnp.asarray(sg["edge_src_local"]),
-        "edge_src_global": jnp.asarray(sg["edge_src_global"]),
-        "edge_dst_global": jnp.asarray(sg["edge_dst_global"]),
-        "edge_src_uid": jnp.asarray(sg["edge_src_uid"]),
-        "edge_dst_uid": jnp.asarray(sg["edge_dst_uid"]),
-        "edge_dst_local": jnp.asarray(sg["edge_dst_local"]),
-        "edge_mask": jnp.asarray(sg["edge_mask"]),
-        "bucket_last_edge": jnp.asarray(sg["bucket_last_edge"]),
-        "bucket_has_edge": jnp.asarray(sg["bucket_has_edge"]),
-        "eprops": jax.tree.map(jnp.asarray, sg["eprops"]),
-    }
+    vprops0 = jax.tree.map(put, sg["vprops_in"])
+    active0 = put(np.ones((Pn, v_pp), bool))
+    edges = {k: put(sg[k]) for k in (
+        "edge_src_local", "edge_src_global", "edge_dst_global",
+        "edge_src_uid", "edge_dst_uid", "edge_dst_local", "edge_mask",
+        "bucket_last_edge", "bucket_has_edge")}
+    edges["eprops"] = jax.tree.map(put, sg["eprops"])
     if pf_blocks is not None:
-        edges["bucket_pf_blocks"] = jnp.asarray(pf_blocks)
-    out_deg_j = jnp.asarray(sg["out_degree"])
-    valid_j = jnp.asarray(sg["vertex_valid"])
-    vids_j = jnp.asarray(sg["vertex_ids"])
+        edges["bucket_pf_blocks"] = put(pf_blocks)
+    out_deg_j = put(sg["out_degree"])
+    valid_j = put(sg["vertex_valid"])
+    vids_j = put(sg["vertex_ids"])
 
     rinfo = {}
     resumed = None
@@ -1200,6 +1196,7 @@ def run_vcprog_distributed(program: vcprog.VCProgram, graph: PropertyGraph,
             resume, vprops0, active0, out_deg_j, valid_j, vids_j)
 
     V = sg["num_vertices"]
+    part_devices = _part_devices(jax.tree.leaves(vprops)[0])
     host = jax.tree.map(
         lambda a: np.asarray(a).reshape((Pn * v_pp,) + a.shape[2:])[:V],
         vprops)
@@ -1216,6 +1213,7 @@ def run_vcprog_distributed(program: vcprog.VCProgram, graph: PropertyGraph,
             "iterations": iterations,
             "active_at_end": active_end,
             "converged": bool(active_end == 0),
+            "part_devices": part_devices,
             "bytes_exchanged": _exchange_bytes_info(
                 program, sg, schedule, frontier, exchange)}
     if resilient:
@@ -1232,6 +1230,22 @@ def run_vcprog_distributed(program: vcprog.VCProgram, graph: PropertyGraph,
         host = host["p"]
         info["batch"] = program.num_lanes
     return host, info
+
+
+def _part_placer(mesh: Mesh):
+    """host [P, ...] array -> device array with part p on mesh device p."""
+    sharding = NamedSharding(mesh, P(AXIS))
+    return lambda a: jax.device_put(np.asarray(a), sharding)
+
+
+def _part_devices(arr) -> list:
+    """Device id holding each part (row block along axis 0) of a
+    part-sharded result, in part order."""
+    rows = {}
+    for shard in arr.addressable_shards:
+        start = shard.index[0].start or 0
+        rows[start] = shard.device.id
+    return [rows[k] for k in sorted(rows)]
 
 
 def _run_resilient(program, graph, sg, edges, mesh, max_iter, schedule,

@@ -86,7 +86,9 @@ def from_edges(
         num_vertices = int(max(src.max(initial=-1), dst.max(initial=-1)) + 1)
     V, E = int(num_vertices), int(src.shape[0])
 
-    order = np.lexsort((src, dst))  # canonical: sort by dst, then src
+    # canonical: sort by dst, then src (stable — the lexsort order, from
+    # one int64 key, about twice as fast on large edge lists)
+    order = np.argsort(dst.astype(np.int64) * max(V, 1) + src, kind="stable")
     src_c, dst_c = src[order], dst[order]
     eprops_c = {k: v[order] for k, v in eprops.items()}
 
@@ -97,7 +99,9 @@ def from_edges(
     out_indptr = np.zeros(V + 1, dtype=np.int64)
     np.cumsum(out_degree, out=out_indptr[1:])
 
-    csc_perm = np.lexsort((dst_c, src_c)).astype(np.int64)  # canonical -> src-sorted
+    # canonical -> src-sorted (by src, then dst; stable)
+    csc_perm = np.argsort(src_c.astype(np.int64) * max(V, 1) + dst_c,
+                          kind="stable")
 
     vprops = {k: np.asarray(v) for k, v in (vertex_props or {}).items()}
     for k, v in vprops.items():

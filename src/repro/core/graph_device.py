@@ -36,8 +36,9 @@ import numpy as np
 from . import vcprog
 from .graph import PropertyGraph
 
-#: edge-block size the scalar-prefetch fused kernel is specialized for;
-#: prefetch window metadata is precomputed host-side against this value.
+#: edge-block size the scalar-prefetch window tables are computed for
+#: host-side; the kernel's edge block spans TILE_1D / PREFETCH_BLOCK_E of
+#: these table blocks (kernels.fused_gather_emit).
 PREFETCH_BLOCK_E = 512
 
 #: default frontier-sparse crossover: the auto dispatch compacts the

@@ -158,9 +158,12 @@ def part_community_graph(num_parts: int, v_per_part: int, degree: int = 8,
 
 def rmat_graph(scale: int, edge_factor: int = 8, seed: int = 0,
                a: float = 0.57, b: float = 0.19, c: float = 0.19,
-               weighted: bool = False) -> PropertyGraph:
+               weighted: bool = False, directed: bool = True
+               ) -> PropertyGraph:
     """RMAT power-law generator (Graph500-style) — skewed degree
-    distributions like the paper's SNAP social graphs."""
+    distributions like the paper's SNAP social graphs. `directed=False`
+    stores both directions of every generated edge (with its weight), as
+    the undirected Graph500 datasets of LDBC Graphalytics are."""
     rng = np.random.default_rng(seed)
     V = 1 << scale
     E = V * edge_factor
@@ -179,4 +182,4 @@ def rmat_graph(scale: int, edge_factor: int = 8, seed: int = 0,
     eprops = None
     if weighted:
         eprops = {"weight": rng.uniform(1.0, 10.0, src.shape[0]).astype(np.float32)}
-    return from_edges(src, dst, V, edge_props=eprops, directed=True)
+    return from_edges(src, dst, V, edge_props=eprops, directed=directed)

@@ -415,11 +415,8 @@ def _has_vector_leaves(program: VCProgram, cv: EdgeLayout, vprops) -> bool:
     from repro.kernels.fused_gather_emit import _emit_schema
     if any(jnp.ndim(a) > 1 for a in jax.tree.leaves(vprops)):
         return True
-    try:
-        emit_sds = _emit_schema(program.emit_message, cv.num_edges, vprops,
-                                cv.eprops)
-    except Exception:
-        return False
+    emit_sds = _emit_schema(program.emit_message, cv.num_edges, vprops,
+                            cv.eprops)
     return any(len(s.shape) > 1 for s in jax.tree.leaves(emit_sds[1]))
 
 
@@ -525,7 +522,7 @@ def _fused_emit_combine(program: VCProgram, layout: EdgeLayout, vprops,
             vprops, layout.eprops, active, layout.num_segments,
             valid=layout.valid_mask,
             src_ids=layout.src_ids, dst_ids=layout.dst_ids,
-            prefetch=prefetch, pack=pack, block_skip=block_skip)
+            pack=pack, block_skip=block_skip)
     else:
         inbox, has_msg = kops.gather_emit_combine(
             program.emit_message, monoids[0], layout.src, layout.dst,

@@ -24,14 +24,9 @@ log = logging.getLogger("repro.sharding")
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False):
-    """Version-tolerant shard_map: `jax.shard_map(check_vma=...)` on new
-    JAX, `jax.experimental.shard_map.shard_map(check_rep=...)` on old."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
+    """`jax.shard_map` with the engines' default of no replication check."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 Candidate = Tuple[str, ...]          # mesh axes fused for one dim
 RuleTable = Dict[str, Sequence[Candidate]]

@@ -2,14 +2,12 @@
 
   fused_gather_emit  the message plane (gather src props -> emit ->
                      combine at dst) as ONE streamed pass — no E-sized
-                     intermediates in HBM
+                     message store in HBM
   segment_reduce     Phase-1 message combine (the paper's scatter hot
-                     loop) as a blocked one-hot MXU matmul (sum) /
-                     segmented-scan + pick matmul (min/max, full
-                     block_e=512)
+                     loop): the fused kernel with an identity emit
   flash_attention    causal GQA flash attention for the LM substrate
 
 Each kernel ships with a pure-jnp oracle in ref.py; ops.py holds the jit'd
-wrappers (interpret=True on CPU).
+wrappers (Mosaic on a TPU, interpret mode elsewhere).
 """
 from . import ops, ref  # noqa: F401

@@ -24,7 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .segment_reduce import _CompilerParams
 
 _NEG_INF = -1e30
 _LANES = 128
@@ -143,7 +142,7 @@ def flash_attention_kernel(q, k, v, causal: bool = True,
             pltpu.VMEM((bq, _LANES), jnp.float32),  # l
             pltpu.VMEM((bq, Dh), jnp.float32),      # acc
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -1,42 +1,30 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-On CPU (this container) the kernels execute with `interpret=True` — the
-kernel body runs in Python, validating the exact TPU code path; on TPU the
-same call sites compile to Mosaic. `interpret=None` means auto-detect.
+On a TPU the kernels compile to Mosaic; on any other backend (the CPU
+test path) they run in interpret mode — the kernel body executes in
+Python, validating the exact TPU code path. The mode follows the backend
+(`fused_gather_emit.interpret_mode`) and is not a caller's choice.
 """
 from __future__ import annotations
-
-import jax
-import jax.numpy as jnp
 
 from . import ref
 from .flash_attention import flash_attention_kernel
 from .fused_gather_emit import gather_emit_combine as _gather_emit_combine
 from .fused_gather_emit import \
     gather_emit_combine_packed as _gather_emit_combine_packed
+from .fused_gather_emit import interpret_mode
 from .segment_reduce import segment_combine_kernel
 
 
-def _auto_interpret(interpret):
-    if interpret is None:
-        return jax.default_backend() == "cpu"
-    return bool(interpret)
-
-
 def segment_combine(vals, seg_ids, num_segments: int, monoid: str = "sum",
-                    interpret=None, **block_kw):
+                    **block_kw):
     """Segment combine of dst-sorted messages; vals [E] or [E, D]."""
-    squeeze = vals.ndim == 1
-    if squeeze:
-        vals = vals[:, None]
-    out = segment_combine_kernel(vals, seg_ids, num_segments, monoid=monoid,
-                                 interpret=_auto_interpret(interpret),
-                                 **block_kw)
-    return out[:, 0] if squeeze else out
+    return segment_combine_kernel(vals, seg_ids, num_segments, monoid=monoid,
+                                  **block_kw)
 
 
 def gather_emit_combine(emit_fn, monoid, src, dst, vprops, eprops, active,
-                        num_vertices: int, interpret=None, **kw):
+                        num_vertices: int, **kw):
     """Fused single-pass gather(src props) -> emit -> segment-combine.
 
     The one-kernel form of the pull-mode message plane; see
@@ -46,32 +34,25 @@ def gather_emit_combine(emit_fn, monoid, src, dst, vprops, eprops, active,
     `block_skip=True` (frontier bitmap early-out of dead edge blocks),
     plus block sizes."""
     return _gather_emit_combine(emit_fn, monoid, src, dst, vprops, eprops,
-                                active, num_vertices,
-                                interpret=_auto_interpret(interpret),
-                                **kw)
+                                active, num_vertices, **kw)
 
 
 def gather_emit_combine_packed(emit_fn, monoids, src, dst, vprops, eprops,
-                               active, num_vertices: int, interpret=None,
-                               **kw):
+                               active, num_vertices: int, **kw):
     """Packed multi-leaf fused pass: whole record in ONE launch, vertex
     props in per-dtype slabs, per-slice monoid table `monoids` (one named
-    monoid per flattened message leaf). Optional kw as above plus
-    `pack=` (a precomputed PackSpec)."""
+    monoid per flattened message leaf). Optional kw as above (no
+    `prefetch`) plus `pack=` (a precomputed PackSpec)."""
     return _gather_emit_combine_packed(emit_fn, monoids, src, dst, vprops,
-                                       eprops, active, num_vertices,
-                                       interpret=_auto_interpret(interpret),
-                                       **kw)
+                                       eprops, active, num_vertices, **kw)
 
 
 def flash_attention(q, k, v, causal: bool = True, window: int | None = None,
-                    sm_scale: float | None = None, interpret=None,
-                    **block_kw):
+                    sm_scale: float | None = None, **block_kw):
     """Causal GQA flash attention; q [B,Hq,T,Dh], k/v [B,Hkv,S,Dh]."""
     return flash_attention_kernel(q, k, v, causal=causal, window=window,
                                   sm_scale=sm_scale,
-                                  interpret=_auto_interpret(interpret),
-                                  **block_kw)
+                                  interpret=interpret_mode(), **block_kw)
 
 
 # re-export oracles for convenience

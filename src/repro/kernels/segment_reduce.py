@@ -2,25 +2,21 @@
 paper's Phase-1 message merging (scatter-combine at dst).
 
 GPU systems scatter messages with atomics; on TPU the idiomatic form is a
-dense *one-hot matmul on the MXU* for sum-monoids; min/max run a segmented
-scan along the edge axis (log2(BE) VPU passes) and then pick each segment's
-last row with a one-hot matmul. Edges arrive dst-sorted (the framework's
-canonical order), so each (segment-block × edge-block) grid cell is usually
-empty — we predicate the compute on block overlap (`@pl.when`), turning
-dst-sortedness into block-sparsity the TPU can skip.
+dense one-hot product per (vertex block, edge block): a matmul on the MXU
+for float sums, a masked select + reduce on the VPU for integer sums and
+min/max. Edges arrive dst-sorted (the framework's canonical order), so
+each vertex block meets only the contiguous run of edge blocks holding
+its in-edges, and the grid visits just those pairs.
 
-All monoids run at the full `block_e` (512 by default): every intermediate
-is 2-D ([BE, BD] scan values or [BE, BV] one-hot picks), never the old
-[BE, BV, BD] mask that capped min/max blocks at 64 edges.
+This is the fused gather–emit–combine kernel with an identity emit over
+already-materialized messages: one kernel body, one grid, one set of
+tiling rules (see fused_gather_emit.py).
 
-Layout: vals [E, D] (messages × payload), seg [E] (dst ids, sorted,
-padding rows carry the sentinel id == V_pad so they never hit a segment),
-out [V, D].
-
-Grid (nv, nd, ne), ne innermost ("arbitrary" = sequential accumulation);
-VMEM scratch acc [BV, BD] carries the partial combine across edge blocks.
-Accumulation dtype: float32 for floating payloads, int32 for integer
-payloads (min/max on int32 ids — e.g. CC labels at 2^31-1 — stays exact).
+Layout: vals [E] or [E, D] (messages x payload), seg [E] (dst ids,
+sorted), out [num_segments] or [num_segments, D]. Accumulation dtype:
+float32 for floating payloads, int32 for integer payloads (min/max on
+int32 ids — e.g. CC labels at 2^31-1 — stays exact); segments with no
+message hold the monoid's identity in the payload dtype.
 """
 from __future__ import annotations
 
@@ -28,151 +24,33 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-_IDENT = {"sum": 0.0, "min": 3.4e38, "max": -3.4e38}
-
-# renamed across JAX versions (TPUCompilerParams -> CompilerParams)
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams")
+from .fused_gather_emit import TILE_1D, gather_emit_combine, \
+    gather_emit_combine_packed
 
 
-def _segmented_scan(vals, seg, ident, op):
-    """Inclusive segmented scan over axis 0 (Hillis-Steele, log2 steps).
-
-    vals [BE, BD], seg [BE] sorted. Returns scan such that scan[e] is the
-    fold of vals over e's segment rows at positions <= e. 2-D throughout.
-    """
-    be = vals.shape[0]
-    pos = jax.lax.broadcasted_iota(jnp.int32, (be, 1), 0)[:, 0]
-    flags = (pos == 0) | (seg != jnp.roll(seg, 1))
-    k = 1
-    while k < be:
-        pv = jnp.roll(vals, k, axis=0)
-        pf = jnp.roll(flags, k)
-        ok = pos >= k
-        pv = jnp.where(ok[:, None], pv, ident)
-        pf = jnp.where(ok, pf, True)
-        vals = jnp.where(flags[:, None], vals, op(vals, pv))
-        flags = flags | pf
-        k *= 2
-    return vals
-
-
-def _kernel(seg_ref, vals_ref, out_ref, acc_ref, *, monoid: str,
-            block_v: int, n_e: int, ident: float):
-    iv = pl.program_id(0)
-    ie = pl.program_id(2)
-
-    @pl.when(ie == 0)
-    def _init():
-        acc_ref[...] = jnp.full_like(acc_ref, ident)
-
-    seg = seg_ref[...]  # [BE] int32 (dst-sorted)
-    v_lo = iv * block_v
-
-    # dst-sortedness => this edge block touches segments [seg[0], seg[-1]];
-    # skip the whole block when it cannot overlap our segment rows.
-    overlap = (seg[-1] >= v_lo) & (seg[0] < v_lo + block_v)
-
-    @pl.when(overlap)
-    def _compute():
-        acc_dtype = acc_ref.dtype
-        vals = vals_ref[...].astype(acc_dtype)  # [BE, BD]
-        be = seg.shape[0]
-        seg_ids = jax.lax.broadcasted_iota(jnp.int32, (be, block_v), 1) + v_lo
-        onehot = (seg[:, None] == seg_ids)  # [BE, BV]
-        if monoid == "sum":
-            # MXU path: out[v, d] += onehot[e, v]^T @ vals[e, d]
-            acc_ref[...] += jax.lax.dot_general(
-                onehot.astype(acc_dtype), vals,
-                dimension_numbers=(((0,), (0,)), ((), ())),
-                preferred_element_type=acc_dtype)
-        else:
-            # segmented scan along the edge axis, then pick each segment's
-            # last in-block row with a one-hot matmul (all 2-D)
-            ident_v = jnp.asarray(ident, acc_dtype)
-            op = jnp.minimum if monoid == "min" else jnp.maximum
-            if acc_dtype == jnp.float32:
-                # the pick matmul multiplies by 0/1 — clamp ±inf (e.g.
-                # bf16 pads that round past its finite range) so inf*0
-                # cannot poison the product with NaN
-                vals = jnp.clip(vals, -_IDENT["min"], _IDENT["min"])
-            scan = _segmented_scan(vals, seg, ident_v, op)  # [BE, BD]
-            pos = jax.lax.broadcasted_iota(jnp.int32, (be, 1), 0)[:, 0]
-            last = (pos == be - 1) | (seg != jnp.roll(seg, -1))
-            pick = onehot & last[:, None]  # [BE, BV]; <=1 hit per column
-            red = jax.lax.dot_general(
-                pick.astype(acc_dtype), scan,
-                dimension_numbers=(((0,), (0,)), ((), ())),
-                preferred_element_type=acc_dtype)  # [BV, BD]
-            has = jnp.any(pick, axis=0)  # [BV]
-            red = jnp.where(has[:, None], red, ident_v)  # 2-D select
-            acc_ref[...] = op(acc_ref[...], red)
-
-    @pl.when(ie == n_e - 1)
-    def _flush():
-        out_ref[...] = acc_ref[...].astype(out_ref.dtype)
+def _identity_emit(s, d, sp, ep):
+    return jnp.bool_(True), ep
 
 
 @functools.partial(
-    jax.jit,
-    static_argnames=("num_segments", "monoid", "block_v", "block_e",
-                     "block_d", "interpret"))
+    jax.jit, static_argnames=("num_segments", "monoid", "block_v",
+                              "block_e"))
 def segment_combine_kernel(vals, seg_ids, num_segments: int,
-                           monoid: str = "sum", block_v: int = 128,
-                           block_e: int = 512, block_d: int = 128,
-                           interpret: bool = False):
-    """vals [E, D] combined into [num_segments, D] under `monoid`.
+                           monoid: str = "sum", block_v: int = TILE_1D,
+                           block_e: int = TILE_1D):
+    """vals [E] / [E, D] combined into [num_segments(, D)] under `monoid`.
 
     seg_ids must be sorted ascending (dst-sorted canonical edge order).
     """
-    E, D = vals.shape
-    bv, be, bd = (min(block_v, _ceil_to(num_segments, 8)),
-                  min(block_e, _ceil_to(E, 8)), min(block_d, _ceil_to(D, 128)))
-
-    # dtype-appropriate monoid identity and accumulator: int payloads keep
-    # the *payload dtype's* iinfo bounds (an int32 ident would wrap when
-    # flushing empty segments back to int8/int16), accumulating in int32;
-    # floats accumulate in f32
-    if jnp.issubdtype(vals.dtype, jnp.integer):
-        info = jnp.iinfo(vals.dtype)
-        ident = {"sum": 0, "min": int(info.max), "max": int(info.min)}[monoid]
-        acc_dtype = jnp.int32
+    E = int(vals.shape[0])
+    src = jnp.zeros((E,), jnp.int32)
+    kw = dict(block_v=block_v, block_e=block_e)
+    if vals.ndim == 1:
+        out, _ = gather_emit_combine(_identity_emit, monoid, src, seg_ids,
+                                     (), vals, None, num_segments, **kw)
     else:
-        ident = _IDENT[monoid]
-        acc_dtype = jnp.float32
-
-    E_pad = max(pl.cdiv(E, be), 1) * be  # E == 0 still needs a flush pass
-    V_pad = pl.cdiv(num_segments, bv) * bv
-    D_pad = pl.cdiv(D, bd) * bd
-
-    vals_p = jnp.pad(vals, ((0, E_pad - E), (0, D_pad - D)),
-                     constant_values=ident)
-    # sentinel id beyond every block's range => padded edges never combine
-    seg_p = jnp.pad(seg_ids.astype(jnp.int32), (0, E_pad - E),
-                    constant_values=jnp.int32(V_pad))
-
-    grid = (V_pad // bv, D_pad // bd, E_pad // be)
-    out = pl.pallas_call(
-        functools.partial(_kernel, monoid=monoid, block_v=bv, n_e=grid[2],
-                          ident=ident),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((be,), lambda iv, id_, ie: (ie,)),
-            pl.BlockSpec((be, bd), lambda iv, id_, ie: (ie, id_)),
-        ],
-        out_specs=pl.BlockSpec((bv, bd), lambda iv, id_, ie: (iv, id_)),
-        out_shape=jax.ShapeDtypeStruct((V_pad, D_pad), vals.dtype),
-        scratch_shapes=[pltpu.VMEM((bv, bd), acc_dtype)],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-        name=f"segment_{monoid}",
-    )(seg_p, vals_p)
-    return out[:num_segments, :D]
-
-
-def _ceil_to(x: int, m: int) -> int:
-    return max(m, ((x + m - 1) // m) * m)
+        out, _ = gather_emit_combine_packed(_identity_emit, (monoid,), src,
+                                            seg_ids, (), vals, None,
+                                            num_segments, **kw)
+    return out

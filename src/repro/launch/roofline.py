@@ -17,18 +17,42 @@ ring conventions:
     collective-permute  operand bytes
 
 Post-SPMD HLO shapes are per-device, so the sums are already per-chip wire
-traffic; the collective term divides by link_bw only. Hardware: TPU v5e —
-197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link ICI (brief's constants).
+traffic; the collective term divides by the chip's interconnect
+bandwidth only. The peaks come from the PEAKS table, keyed by the device
+kind JAX reports.
 """
 from __future__ import annotations
 
 import dataclasses
 import re
-from typing import Dict, List
+from typing import Dict, NamedTuple
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-LINK_BW = 50e9
+
+class Peaks(NamedTuple):
+    flops: float      # dense bf16 FLOP/s per chip
+    hbm_bw: float     # HBM bytes/s per chip
+    ici_bw: float     # chip-to-chip interconnect bytes/s per chip
+    source: str
+
+
+#: published per-chip peaks, keyed by `jax.devices()[0].device_kind`
+PEAKS: Dict[str, Peaks] = {
+    "TPU v5 lite": Peaks(
+        flops=197e12, hbm_bw=819e9, ici_bw=1600e9 / 8,
+        source="Google Cloud documentation, 'TPU v5e': 197 TFLOP/s bf16, "
+               "16 GB HBM at 819 GB/s, 1,600 Gbit/s chip-to-chip "
+               "interconnect"),
+}
+
+
+def peaks_for(device_kind: str) -> Peaks:
+    """The peak table row of a device kind; an unknown kind is an error,
+    never a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r}; known: {sorted(PEAKS)}") from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "f16": 2, "bf16": 2,
@@ -113,18 +137,23 @@ class Roofline:
     # behind the bucket plane passes, so the step is max(local, wire)
     # instead of local + wire. See step_s.
     overlap: bool = True
+    device_kind: str = "TPU v5 lite"
+
+    @property
+    def peaks(self) -> Peaks:
+        return peaks_for(self.device_kind)
 
     @property
     def compute_s(self) -> float:
-        return self.flops / PEAK_FLOPS
+        return self.flops / self.peaks.flops
 
     @property
     def memory_s(self) -> float:
-        return self.hbm_bytes / HBM_BW
+        return self.hbm_bytes / self.peaks.hbm_bw
 
     @property
     def collective_s(self) -> float:
-        return self.wire_bytes * self.wire_codec_ratio / LINK_BW
+        return self.wire_bytes * self.wire_codec_ratio / self.peaks.ici_bw
 
     @property
     def step_s(self) -> float:
@@ -155,13 +184,15 @@ class Roofline:
         under the overlapped schedules (the default), local + wire
         otherwise — both variants are reported in EXPERIMENTS."""
         t = self.step_s
-        return (self.model_flops / (self.chips * PEAK_FLOPS)) / t if t else 0.0
+        return ((self.model_flops / (self.chips * self.peaks.flops)) / t
+                if t else 0.0)
 
     def to_dict(self) -> dict:
         return {
             "flops": self.flops, "hbm_bytes": self.hbm_bytes,
             "wire_bytes_per_chip": self.wire_bytes, "chips": self.chips,
             "model_flops": self.model_flops,
+            "device_kind": self.device_kind,
             "compute_s": self.compute_s, "memory_s": self.memory_s,
             "collective_s": self.collective_s,
             "wire_codec_ratio": self.wire_codec_ratio,
@@ -175,7 +206,8 @@ class Roofline:
 
 
 def analyze(compiled, chips: int, model_flops: float,
-            wire_codec_ratio: float = 1.0, overlap: bool = True) -> Roofline:
+            wire_codec_ratio: float = 1.0, overlap: bool = True,
+            device_kind: str = "TPU v5 lite") -> Roofline:
     cost = compiled.cost_analysis()
     if isinstance(cost, list):  # older jax returns [dict]
         cost = cost[0]
@@ -185,4 +217,5 @@ def analyze(compiled, chips: int, model_flops: float,
     wire = sum(d["wire_bytes"] for d in colls.values())
     return Roofline(flops=flops, hbm_bytes=hbm, wire_bytes=wire, chips=chips,
                     model_flops=model_flops, collectives=colls,
-                    wire_codec_ratio=wire_codec_ratio, overlap=overlap)
+                    wire_codec_ratio=wire_codec_ratio, overlap=overlap,
+                    device_kind=device_kind)

@@ -56,6 +56,8 @@ def main(argv=None):
                     help="tiny graph + short stream (CI)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    from repro.envutil import enable_compile_cache
+    enable_compile_cache()
 
     if args.smoke:
         args.num_vertices = min(args.num_vertices, 2_000)

@@ -71,8 +71,9 @@ def test_collective_parser_conventions():
 
 
 def test_roofline_terms_and_bottleneck():
-    rf = RL.Roofline(flops=197e12 * 0.01, hbm_bytes=819e9 * 0.05,
-                     wire_bytes=50e9 * 0.002, chips=256,
+    pk = RL.peaks_for("TPU v5 lite")
+    rf = RL.Roofline(flops=pk.flops * 0.01, hbm_bytes=pk.hbm_bw * 0.05,
+                     wire_bytes=pk.ici_bw * 0.002, chips=256,
                      model_flops=197e12 * 0.008 * 256, collectives={})
     assert abs(rf.compute_s - 0.01) < 1e-9
     assert abs(rf.memory_s - 0.05) < 1e-9
@@ -80,6 +81,8 @@ def test_roofline_terms_and_bottleneck():
     assert rf.bottleneck == "memory"
     assert abs(rf.useful_compute_ratio - 0.8) < 1e-6
     assert abs(rf.roofline_fraction - 0.16) < 1e-6
+    with pytest.raises(ValueError, match="no published peaks"):
+        RL.peaks_for("TPU v0 imaginary")
 
 
 def test_mesh_shapes():

@@ -60,9 +60,9 @@ def test_segment_combine_1d_and_empty_segments():
 
 @pytest.mark.parametrize("monoid", ["min", "max"])
 def test_segment_combine_minmax_full_block_e(monoid):
-    """min/max must run the segmented-scan path at the FULL block_e=512
-    (the old 3-D mask intermediate capped them at 64 edges/block)."""
-    E, V, D = 1600, 96, 4  # several 512-edge blocks, segments span blocks
+    """min/max over several edge blocks whose segments span block
+    boundaries (a requested block_e=512 is raised to the 1-D tile)."""
+    E, V, D = 2600, 96, 4  # several 1024-edge blocks, segments span blocks
     seg = np.sort(RNG.integers(0, V, E)).astype(np.int32)
     vals = RNG.normal(size=(E, D)).astype(np.float32)
     out = ops.segment_combine(jnp.asarray(vals), jnp.asarray(seg), V,
