@@ -14,6 +14,7 @@ from typing import Any, Dict
 import jax
 import jax.numpy as jnp
 
+from ... import obs
 from .. import message_plane, records, vcprog
 from ..graph import PropertyGraph
 from ..graph_device import DeviceGraph, build_device_graph
@@ -74,12 +75,16 @@ def _make_step(program, graph: DeviceGraph, engine, kernel_on: bool,
     return step
 
 
-def _finish(graph: DeviceGraph, state):
+def _finish(graph: DeviceGraph, state, engine):
+    """(vprops, supersteps, active count, active-edge tally); the tally is
+    `()` for engines that keep none (see pushpull.tally_value)."""
     final_it, vprops, active = state[0], state[1], state[2]
     if graph.inv_perm is not None:
         # un-permute: row old_id of the result lives at new_id=inv_perm[old]
         vprops = records.tree_gather(vprops, graph.inv_perm)
-    return vprops, final_it - 1, jnp.sum(active)
+    tally = getattr(engine, "active_edge_tally", None)
+    return (vprops, final_it - 1, jnp.sum(active),
+            () if tally is None else tally(state[5]))
 
 
 def _run_compiled(program, graph: DeviceGraph, max_iter: int, engine,
@@ -88,7 +93,7 @@ def _run_compiled(program, graph: DeviceGraph, max_iter: int, engine,
     step = _make_step(program, graph, engine, kernel_on, frontier, prefetch)
     state = vcprog.run_loop(step, _init_state(program, graph, engine,
                                               kernel_on), max_iter)
-    return _finish(graph, state)
+    return _finish(graph, state, engine)
 
 
 def _bind_lanes(program, lanes):
@@ -108,6 +113,7 @@ def _jitted_runner(engine_name: str, program_key, max_iter: int,
     engine = ENGINES[engine_name]
     program = program_key.program
 
+    @obs.scope(obs.VERTEX)
     def run(graph: DeviceGraph, lanes=()):
         return _run_compiled(_bind_lanes(program, lanes), graph, max_iter,
                              engine, kernel_on, frontier, prefetch)
@@ -166,6 +172,7 @@ def _jitted_warm_runner(engine_name: str, program_key, max_iter: int,
     engine = ENGINES[engine_name]
     program = program_key.program
 
+    @obs.scope(obs.VERTEX)
     def run(graph: DeviceGraph, lanes, vprops0, active0):
         prog = _bind_lanes(program, lanes)
         step = _make_step(prog, graph, engine, kernel_on, frontier, prefetch)
@@ -173,7 +180,7 @@ def _jitted_warm_runner(engine_name: str, program_key, max_iter: int,
             step, _warm_entry_state(prog, graph, engine, kernel_on,
                                     frontier, prefetch, vprops0, active0),
             max_iter)
-        return _finish(graph, state)
+        return _finish(graph, state, engine)
 
     return jax.jit(run)
 
@@ -196,10 +203,12 @@ def _chunked_runner(engine_name: str, program_key, kernel_on: bool,
     program = program_key.program
     vspecs = faults_mod.vprop_faults(fault_specs)
 
+    @obs.scope(obs.VERTEX)
     def init(graph: DeviceGraph, lanes=()):
         return _init_state(_bind_lanes(program, lanes), graph, engine,
                            kernel_on)
 
+    @obs.scope(obs.VERTEX)
     def chunk(graph: DeviceGraph, lanes, state, limit, fault_on):
         step = _make_step(_bind_lanes(program, lanes), graph, engine,
                           kernel_on, frontier, prefetch)
@@ -228,8 +237,9 @@ def _chunked_runner(engine_name: str, program_key, kernel_on: bool,
             tuple(state) + (jnp.zeros((faults_mod.NUM_ALARMS,), jnp.int32),))
         return out[:-1], out[-1]
 
+    @obs.scope(obs.VERTEX)
     def finish(graph: DeviceGraph, state):
-        return _finish(graph, tuple(state))
+        return _finish(graph, tuple(state), engine)
 
     return jax.jit(init), jax.jit(chunk), jax.jit(finish)
 
@@ -322,9 +332,82 @@ def _run_lane_chunked(program, graph, max_iter, *, engine, kernel,
     info["converged"] = all(i["converged"] for i in infos)
     info["batch"] = program.num_lanes
     info["lane_chunks"] = {"width": int(chunk_width), "chunks": len(infos)}
+    for key in ("active_edges", "edge_slots"):
+        if key in info:
+            info[key] = sum(i[key] for i in infos)
     return vprops, info
 
 
+def _edge_counts(tally, num_edges: int, supersteps: int) -> dict:
+    """`info["active_edges"]` (slots whose source was on the frontier,
+    summed over the supersteps) and `info["edge_slots"]` (stored slots x
+    supersteps), added to the process's `obs` counters; nothing for
+    engines that keep no tally."""
+    if isinstance(tally, tuple):
+        return {}
+    from .pushpull import tally_value
+    counts = {"active_edges": tally_value(tally),
+              "edge_slots": int(num_edges) * int(supersteps)}
+    obs.add(obs.ACTIVE_EDGES, counts["active_edges"])
+    obs.add(obs.EDGE_SLOTS, counts["edge_slots"])
+    return counts
+
+
+def _run_chunked(program, graph, gdev, pkey, max_iter: int, *, engine,
+                 kernel_on, reorder, frontier, prefetch, checkpoint_dir,
+                 checkpoint_every, resume, guards_on, fault_specs):
+    """The resilient path of `run_vcprog`: host-level rounds of supersteps
+    with snapshots, guards and faults. Returns the runner's output and
+    its info keys."""
+    from repro import checkpoint as ckpt
+    from repro.distributed import faults as faults_mod
+    if faults_mod.wire_faults(fault_specs):
+        raise ValueError(
+            "wire faults (flip_bits/drop_delta) need "
+            "engine='distributed' — single-device engines have no "
+            "delta exchange to corrupt")
+    init_j, chunk_j, finish_j = _chunked_runner(
+        engine, pkey, kernel_on, frontier, prefetch,
+        guards_on, fault_specs)
+    state = init_j(gdev, pkey.lane_values)
+    mgr = resumed = save_cb = None
+    if checkpoint_dir:
+        # max_iter deliberately NOT in the fingerprint: a truncated
+        # run may resume with a higher budget (the kill→resume tests)
+        fp = {"graph": ckpt.graph_signature(graph), "engine": engine,
+              "program": ckpt.program_signature(program),
+              "reorder": reorder, "kernel": bool(kernel_on),
+              "layout": "device", "format": 2}
+        mgr = ckpt.CheckpointManager(checkpoint_dir)
+        step0 = ckpt.resume_step(mgr, fp, resume)
+        if step0 is not None:
+            state = mgr.restore(tuple(state), step0)
+            resumed = step0
+
+        def save_cb(st, done):
+            mgr.save(done, tuple(st), metadata={"fingerprint": fp})
+
+    def chunk(st, limit, f_on):
+        return chunk_j(gdev, pkey.lane_values, tuple(st),
+                       jnp.int32(limit), jnp.int32(f_on))
+
+    def probe(st):
+        it = int(jax.device_get(st[0]))
+        live = (int(jnp.sum(jnp.asarray(st[2]))) +
+                int(jnp.sum(jnp.asarray(st[4])))) > 0
+        return it, live
+
+    state, rinfo = faults_mod.drive_chunks(
+        chunk, state, max_iter=max_iter,
+        every=int(checkpoint_every or 0), probe=probe, save=save_cb,
+        flush=(mgr.wait if mgr is not None else None),
+        guards_on=guards_on, faults=fault_specs, degrade=None)
+    if mgr is not None:
+        mgr.wait()
+    return finish_j(gdev, tuple(state)), {"resumed_from": resumed, **rinfo}
+
+
+@obs.job()
 def run_vcprog(program: vcprog.VCProgram, graph: PropertyGraph, max_iter: int,
                engine: str = "pushpull", kernel: str | bool = "auto",
                use_kernel: bool | None = None, reorder: str = "none",
@@ -415,7 +498,6 @@ def run_vcprog(program: vcprog.VCProgram, graph: PropertyGraph, max_iter: int,
     This is the single-device path; `repro.core.engines.distributed` provides
     the shard_map multi-device path with identical semantics.
     """
-    from repro import checkpoint as ckpt
     from repro.distributed import faults as faults_mod, wire
     from ..graph_device import resolve_lane_chunk
     frontier = message_plane.resolve_frontier_mode(frontier)
@@ -461,81 +543,40 @@ def run_vcprog(program: vcprog.VCProgram, graph: PropertyGraph, max_iter: int,
                  "prefetch_windows": None, "exchange": exchange,
                  "overlap": bool(overlap),
                  "bytes_exchanged": local_bytes_info()}
-    if warm_start is not None:
-        if resilient:
-            raise ValueError(
-                "warm_start does not compose with checkpointing/guards/"
-                "faults — re-converge cold under those, or warm without")
-        wv, wa = warm_start
-        runner = _jitted_warm_runner(engine, pkey, int(max_iter),
-                                     kernel_on, frontier, prefetch)
-        vprops, iters, num_active = runner(gdev, pkey.lane_values, wv, wa)
+    with obs.span(obs.RUN):
+        if warm_start is not None:
+            if resilient:
+                raise ValueError(
+                    "warm_start does not compose with checkpointing/guards/"
+                    "faults — re-converge cold under those, or warm without")
+            wv, wa = warm_start
+            runner = _jitted_warm_runner(engine, pkey, int(max_iter),
+                                         kernel_on, frontier, prefetch)
+            out = runner(gdev, pkey.lane_values, wv, wa)
+            run_info = {"warm_start": True}
+        elif not resilient:
+            runner = _jitted_runner(engine, pkey, int(max_iter),
+                                    kernel_on, frontier, prefetch)
+            out = runner(gdev, pkey.lane_values)
+            run_info = {}
+        else:
+            out, run_info = _run_chunked(
+                program, graph, gdev, pkey, int(max_iter), engine=engine,
+                kernel_on=kernel_on, reorder=reorder, frontier=frontier,
+                prefetch=prefetch, checkpoint_dir=checkpoint_dir,
+                checkpoint_every=checkpoint_every, resume=resume,
+                guards_on=guards_on, fault_specs=fault_specs)
+        vprops, iters, num_active, tally = out
         info = {**base_info, "iterations": int(iters),
                 "active_at_end": int(num_active),
-                "converged": bool(int(num_active) == 0),
-                "warm_start": True}
-    elif not resilient:
-        runner = _jitted_runner(engine, pkey, int(max_iter),
-                                kernel_on, frontier, prefetch)
-        vprops, iters, num_active = runner(gdev, pkey.lane_values)
-        info = {**base_info, "iterations": int(iters),
-                "active_at_end": int(num_active),
-                "converged": bool(int(num_active) == 0)}
-    else:
-        if faults_mod.wire_faults(fault_specs):
-            raise ValueError(
-                "wire faults (flip_bits/drop_delta) need "
-                "engine='distributed' — single-device engines have no "
-                "delta exchange to corrupt")
-        init_j, chunk_j, finish_j = _chunked_runner(
-            engine, pkey, kernel_on, frontier, prefetch,
-            guards_on, fault_specs)
-        state = init_j(gdev, pkey.lane_values)
-        mgr = resumed = save_cb = None
-        if checkpoint_dir:
-            # max_iter deliberately NOT in the fingerprint: a truncated
-            # run may resume with a higher budget (the kill→resume tests)
-            fp = {"graph": ckpt.graph_signature(graph), "engine": engine,
-                  "program": ckpt.program_signature(program),
-                  "reorder": reorder, "kernel": bool(kernel_on),
-                  "layout": "device", "format": 1}
-            mgr = ckpt.CheckpointManager(checkpoint_dir)
-            step0 = ckpt.resume_step(mgr, fp, resume)
-            if step0 is not None:
-                state = mgr.restore(tuple(state), step0)
-                resumed = step0
-
-            def save_cb(st, done):
-                mgr.save(done, tuple(st), metadata={"fingerprint": fp})
-
-        def chunk(st, limit, f_on):
-            return chunk_j(gdev, pkey.lane_values, tuple(st),
-                           jnp.int32(limit), jnp.int32(f_on))
-
-        def probe(st):
-            it = int(jax.device_get(st[0]))
-            live = (int(jnp.sum(jnp.asarray(st[2]))) +
-                    int(jnp.sum(jnp.asarray(st[4])))) > 0
-            return it, live
-
-        state, rinfo = faults_mod.drive_chunks(
-            chunk, state, max_iter=int(max_iter),
-            every=int(checkpoint_every or 0), probe=probe, save=save_cb,
-            flush=(mgr.wait if mgr is not None else None),
-            guards_on=guards_on, faults=fault_specs, degrade=None)
-        if mgr is not None:
-            mgr.wait()
-        vprops, iters, num_active = finish_j(gdev, tuple(state))
-        info = {**base_info, "iterations": int(iters),
-                "active_at_end": int(num_active),
-                "converged": bool(int(num_active) == 0),
-                "resumed_from": resumed, **rinfo}
+                "converged": bool(int(num_active) == 0), **run_info,
+                **_edge_counts(tally, gdev.num_edges, int(iters))}
     if not info["converged"]:
         warnings.warn(
             f"run_vcprog hit max_iter={int(max_iter)} with "
             f"{info['active_at_end']} vertices still active — the result "
             "is truncated, not converged (info['converged'] is False)",
-            faults_mod.NonConvergenceWarning, stacklevel=2)
+            faults_mod.NonConvergenceWarning, stacklevel=3)
     if isinstance(program, vcprog.BatchedProgram):
         # un-wrap the lane axis: the user sees the base record with [V, Q]
         # leaves (the `_lane_act` bookkeeping column stays internal)
@@ -556,8 +597,10 @@ def compiled_runner(program, engine: str = "pushpull", max_iter: int = 100,
     Returns (runner, lane_values):
       * cold (warm=False):  runner(gdev, lane_values)
       * warm (warm=True):   runner(gdev, lane_values, vprops0, active0)
-    both yielding the raw (vprops, final_iterations, num_active) triple —
-    batched programs return the WRAPPED record (caller unwraps ["p"]).
+    both yielding the raw (vprops, final_iterations, num_active, tally)
+    tuple (tally: the engine's active-edge count, `()` where it keeps
+    none) — batched programs return the WRAPPED record (caller unwraps
+    ["p"]).
     The runner is the same object `run_vcprog` would use (one shared
     lru_cache), so holding it in a serving cache and calling it directly
     skips every per-request resolution/dispatch layer while staying

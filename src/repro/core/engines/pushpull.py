@@ -13,6 +13,11 @@ receives:
                eligible.
 
 Heuristic (Gemini): push when `sum(out_degree[active]) < |E| / alpha`.
+
+The loop carry (`extra`) tallies that sum over the supersteps: the edge
+slots whose source was on the frontier, i.e. the useful part of the
+slots the plane streams (`info["active_edges"]`, counter
+`obs.ACTIVE_EDGES`).
 """
 from __future__ import annotations
 
@@ -22,13 +27,34 @@ import jax.numpy as jnp
 from .. import message_plane, vcprog
 from .common import register
 
+#: the tally is [lo, hi] int32 with count = hi * 2**LOW_BITS + lo, so it
+#: stays exact past 2**31 (100 supersteps of 2**31 slots)
+LOW_BITS = 30
+
+
+def _tally(carry, n):
+    """carry + n for a non-negative int32 n; lo stays below 2**LOW_BITS,
+    so lo + n fits uint32."""
+    s = carry[0].astype(jnp.uint32) + n.astype(jnp.uint32)
+    lo = (s & ((1 << LOW_BITS) - 1)).astype(jnp.int32)
+    return jnp.stack([lo, carry[1] + (s >> LOW_BITS).astype(jnp.int32)])
+
+
+def tally_value(carry) -> int:
+    """The tally as a Python int (syncs with the device)."""
+    lo, hi = (int(x) for x in jax.device_get(carry))
+    return (hi << LOW_BITS) | lo
+
 
 @register("pushpull")
 class PushPullEngine:
     alpha: float = 20.0
 
     def init_extra(self, graph, program, vprops0, kernel_on):
-        return ()
+        return jnp.zeros((2,), jnp.int32)
+
+    def active_edge_tally(self, extra):
+        return extra
 
     def emit_and_combine(self, graph, program, vprops, active, extra, empty,
                          kernel_on, frontier="dense", prefetch="auto"):
@@ -47,4 +73,4 @@ class PushPullEngine:
                 kernel_on=kernel_on, frontier=frontier, prefetch=prefetch)
 
         inbox, has_msg = jax.lax.cond(use_push, push, pull, operand=None)
-        return inbox, has_msg, extra
+        return inbox, has_msg, _tally(extra, active_out_edges)

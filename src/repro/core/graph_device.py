@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from . import vcprog
 from .graph import PropertyGraph
 
@@ -319,21 +320,43 @@ def build_device_graph(g: PropertyGraph,
     SegmentMeta / prefetch windows) then describe the reordered edges,
     while the ORIGINAL ids ride the layouts' `src_ids`/`dst_ids` so the
     user's `emit_message` never sees the relabeling.
+
+    Host spans: `obs.PREPARE` around it all, with the children
+    `PREPARE_LAYOUTS` (relabeling, src-sorted order, inverse CSC,
+    last_edge), `PREPARE_WINDOWS` (the window table) and `PREPARE_UPLOAD`
+    (the host-to-device transfers).
     """
+    with obs.span(obs.PREPARE):
+        with obs.span(obs.PREPARE_LAYOUTS):
+            g, perm_np, inv_np, layouts = _host_layouts(g, reorder)
+        with obs.span(obs.PREPARE_WINDOWS):
+            windows = compute_prefetch_windows(g.src, int(g.num_vertices))
+        with obs.span(obs.PREPARE_UPLOAD):
+            return _upload(g, perm_np, inv_np, layouts, windows)
+
+
+def _host_layouts(g: PropertyGraph, reorder: str):
+    """The relabeled graph, its permutations, and the host arrays of the
+    src-sorted layout and segment structure."""
     perm_np = inv_np = None
     if reorder not in (None, "none"):
         from .reorder import apply_reorder
         g, perm_np, inv_np = apply_reorder(g, reorder)
-
     src_s, dst_s, eprops_s = g.src_sorted()
     inv_csc = np.empty_like(g.csc_perm)
     inv_csc[g.csc_perm] = np.arange(g.csc_perm.shape[0])
+    last_edge = np.clip(g.in_indptr[1:] - 1, 0, max(g.num_edges - 1, 0))
+    return g, perm_np, inv_np, (src_s, dst_s, eprops_s, inv_csc, last_edge)
+
+
+def _upload(g: PropertyGraph, perm_np, inv_np, layouts, windows
+            ) -> DeviceGraph:
+    src_s, dst_s, eprops_s, inv_csc, last_edge = layouts
+    pf_blocks, pf_window = windows
     V, E = int(g.num_vertices), int(g.num_edges)
-    last_edge = np.clip(g.in_indptr[1:] - 1, 0, max(E - 1, 0))
     meta = vcprog.SegmentMeta(
         last_edge=jnp.asarray(last_edge.astype(np.int32)),
         has_edge=jnp.asarray(g.in_degree > 0))
-    pf_blocks, pf_window = compute_prefetch_windows(g.src, V)
 
     # original (user-visible) endpoint ids of the relabeled edges
     uid = (lambda a: None) if perm_np is None else (

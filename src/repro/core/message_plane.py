@@ -47,6 +47,12 @@ sparse compaction keep every block/edge that ANY unconverged lane still
 needs; converged lanes emit exact monoid identities, so their folds are
 per-lane no-ops and each lane's result stays bit-identical to its own
 sequential run.
+
+Device scopes (`repro.obs`): `PLANE_GATHER` holds every gather of vertex
+values or the frontier into edge order (and the unfused emit evaluated
+on them), `PLANE_OPERANDS` the per-pass edge operands and scalar tables,
+`PLANE_KERNEL` the Pallas calls and the fused pass's empty-record fill,
+`PLANE_COMBINE` the unfused permute and segment combine.
 """
 from __future__ import annotations
 
@@ -55,6 +61,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from .. import obs
 from . import records
 from .graph_device import EdgeLayout, SPARSE_CAP_FRAC, workset_capacity
 from .knobs import knob_error
@@ -247,6 +254,7 @@ def _segment_named(program: VCProgram, msgs: RecordBatch, dst: jnp.ndarray,
     return inbox, _has_msg(valid, dst, num_segments)
 
 
+@obs.scope(obs.PLANE_COMBINE)
 def segment_combine(program: VCProgram, msgs, dst, valid, num_segments, empty,
                     kernel_on: bool = False,
                     meta: Optional[SegmentMeta] = None):
@@ -316,36 +324,40 @@ def _sparse_emit_combine(program: VCProgram, cv: EdgeLayout, vprops,
     monoid, skipped slots contribute only identities).
     """
     E, V = cv.num_edges, cv.num_segments
-    ws, count = compact_indices(act_e, cap)
-    ws_valid = jnp.arange(cap, dtype=jnp.int32) < count
-    wsc = jnp.minimum(ws, max(E - 1, 0))  # clip sentinel pads for gathers
-    src_ws = jnp.take(cv.src, wsc, axis=0)
-    dst_ws = jnp.where(ws_valid, jnp.take(cv.dst, wsc, axis=0),
-                       jnp.int32(V))
-    sid_ws = jnp.take(cv.emit_src_ids, wsc, axis=0)
-    did_ws = jnp.where(ws_valid, jnp.take(cv.emit_dst_ids, wsc, axis=0),
-                       jnp.int32(V))
-    src_prop = records.tree_gather(vprops, src_ws)
-    eprops_ws = records.tree_gather(cv.eprops, wsc)
-    is_emit, msgs = jax.vmap(program.emit_message)(sid_ws, did_ws, src_prop,
-                                                   eprops_ws)
-    valid = is_emit.astype(bool) & ws_valid  # act already folded into flags
-    # workset segment structure is dynamic (changes every superstep) —
-    # derived in-trace at O(cap), unlike the loop-constant dense meta
-    meta = make_segment_meta(dst_ws, V, valid=valid)
-    seg_op = None
-    if kernel_on:
-        from repro.kernels import ops as kops
-        seg_op = lambda x, monoid: kops.segment_combine(
-            x, dst_ws, V, monoid=monoid)
-    return _segment_named(program, msgs, dst_ws, valid, V, empty, meta,
-                          monoids, seg_op=seg_op)
+    with obs.scope(obs.PLANE_OPERANDS):
+        ws, count = compact_indices(act_e, cap)
+        ws_valid = jnp.arange(cap, dtype=jnp.int32) < count
+        wsc = jnp.minimum(ws, max(E - 1, 0))  # clip sentinel pads
+    with obs.scope(obs.PLANE_GATHER):
+        src_ws = jnp.take(cv.src, wsc, axis=0)
+        dst_ws = jnp.where(ws_valid, jnp.take(cv.dst, wsc, axis=0),
+                           jnp.int32(V))
+        sid_ws = jnp.take(cv.emit_src_ids, wsc, axis=0)
+        did_ws = jnp.where(ws_valid, jnp.take(cv.emit_dst_ids, wsc, axis=0),
+                           jnp.int32(V))
+        src_prop = records.tree_gather(vprops, src_ws)
+        eprops_ws = records.tree_gather(cv.eprops, wsc)
+        is_emit, msgs = jax.vmap(program.emit_message)(sid_ws, did_ws,
+                                                       src_prop, eprops_ws)
+        valid = is_emit.astype(bool) & ws_valid  # act folded into flags
+    with obs.scope(obs.PLANE_COMBINE):
+        # workset segment structure is dynamic (changes every superstep) —
+        # derived in-trace at O(cap), unlike the loop-constant dense meta
+        meta = make_segment_meta(dst_ws, V, valid=valid)
+        seg_op = None
+        if kernel_on:
+            from repro.kernels import ops as kops
+            seg_op = lambda x, monoid: kops.segment_combine(
+                x, dst_ws, V, monoid=monoid)
+        return _segment_named(program, msgs, dst_ws, valid, V, empty, meta,
+                              monoids, seg_op=seg_op)
 
 
 # ---------------------------------------------------------------------------
 # Layout-level dataflow pieces (what engines compose)
 # ---------------------------------------------------------------------------
 
+@obs.scope(obs.PLANE_GATHER)
 def edge_active(layout: EdgeLayout, active) -> jnp.ndarray:
     """Per-edge frontier flags in LAYOUT order: src on the frontier and
     the slot not padding. Computed ONCE per plane invocation and shared
@@ -358,6 +370,7 @@ def edge_active(layout: EdgeLayout, active) -> jnp.ndarray:
     return flags
 
 
+@obs.scope(obs.PLANE_GATHER)
 def emit_messages(program: VCProgram, layout: EdgeLayout, vprops, active,
                   src_active=None) -> Tuple[RecordBatch, jnp.ndarray]:
     """Phase 3 on the layout's own edge order: gather src props, vmap the
@@ -376,6 +389,7 @@ def emit_messages(program: VCProgram, layout: EdgeLayout, vprops, active,
     return msgs, valid
 
 
+@obs.scope(obs.PLANE_COMBINE)
 def combine(program: VCProgram, layout: EdgeLayout, msgs, valid, empty,
             kernel_on: bool = False) -> Tuple[RecordBatch, jnp.ndarray]:
     """Phase 1: fold layout-ordered messages into per-vertex inboxes.
@@ -476,6 +490,7 @@ def _per_leaf_fused(program: VCProgram, layout: EdgeLayout, vprops, active,
     return jax.tree.unflatten(mdef, out_leaves), has_msg
 
 
+@obs.scope(obs.PLANE_KERNEL)
 def _fused_emit_combine(program: VCProgram, layout: EdgeLayout, vprops,
                         active, empty: Record, multileaf: str = "auto",
                         block_skip: bool = False,
@@ -621,8 +636,9 @@ def emit_and_combine(program: VCProgram, layout: EdgeLayout, vprops, active,
     if (frontier != "dense" and monoids is not None
             and cv.num_edges > 0 and cv.num_segments > 0):
         # frontier flags in combine order (one permute of the hoisted mask)
-        act_e = (src_active if layout.perm is None
-                 else jnp.take(src_active, layout.perm, axis=0))
+        with obs.scope(obs.PLANE_GATHER):
+            act_e = (src_active if layout.perm is None
+                     else jnp.take(src_active, layout.perm, axis=0))
         cap = workset_capacity(
             cv.num_edges, 1.0 if frontier == "sparse" else SPARSE_CAP_FRAC)
         sparse_fn = lambda _: _sparse_emit_combine(
@@ -635,7 +651,8 @@ def emit_and_combine(program: VCProgram, layout: EdgeLayout, vprops, active,
                                         src_active=src_active)
             return combine(program, layout, msgs, valid, empty, kernel_on)
 
-        n_act = jnp.sum(act_e.astype(jnp.int32))
+        with obs.scope(obs.PLANE_OPERANDS):
+            n_act = jnp.sum(act_e.astype(jnp.int32))
         return jax.lax.cond(n_act <= cap, sparse_fn, dense_fn, operand=None)
 
     msgs, valid = emit_messages(program, layout, vprops, active,

@@ -56,6 +56,10 @@ tiled T(1024), so 1-D edge and vertex blocks are multiples of TILE_1D (or
 the whole array). The packed variant keeps its slabs lane-major
 ([W, N]: record columns on sublanes, vertices or edges on lanes), so
 narrow records are not padded to 128 lanes in HBM.
+
+Device scopes (`repro.obs`): a pass runs under `PLANE_KERNEL`; its XLA
+gathers of vertex rows into edge order under `PLANE_GATHER`, and the
+padded edge operands and scalar tables under `PLANE_OPERANDS`.
 """
 from __future__ import annotations
 
@@ -66,6 +70,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .. import obs
 
 _F32_IDENT = {"sum": 0.0, "min": 3.4e38, "max": -3.4e38}
 _NAMED = ("sum", "min", "max")
@@ -160,6 +166,7 @@ def _plan(E: int, V: int, block_v: int, block_e: int,
     return _Plan(bv=bv, be=be, n_vb=n_vb, n_e=n_e, shift=shift)
 
 
+@obs.scope(obs.PLANE_OPERANDS)
 def _visit_table(seg_p, p: _Plan):
     """[n_steps + 1] int32: the (vb, eb) pairs the grid visits, in order,
     as ``vb << shift | eb``; the last entry is the live step count. Vertex
@@ -204,6 +211,7 @@ def _v_map(p: _Plan):
     return lambda t, code, *_: (code[t] >> p.shift,)
 
 
+@obs.scope(obs.PLANE_OPERANDS)
 def _block_active(active, src, valid, pad_e, n_e: int, be: int):
     """Per-edge-block frontier bitmap [n_e] int32: does any edge in the
     block have an active src (and a valid slot)? Computed on device each
@@ -477,6 +485,7 @@ def _kernel(*refs, emit_fn, monoid, n_vp, n_ep, n_msg, vp_def, ep_def,
         hm_out[...] = hm_acc[0]
 
 
+@obs.scope(obs.PLANE_KERNEL)
 def _pallas(body, p: _Plan, scalar_ops, operands, in_specs, out_specs,
             out_shape, scratch, name):
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -492,6 +501,7 @@ def _pallas(body, p: _Plan, scalar_ops, operands, in_specs, out_specs,
     )(*scalar_ops, *operands)
 
 
+@obs.scope(obs.PLANE_OPERANDS)
 def _edge_operands(p: _Plan, src, dst, valid, src_ids, dst_ids, e_spec):
     """The edge-streamed id/mask operands every variant starts with."""
     pad_e = lambda a, fill: jnp.pad(a, (0, p.E_pad - a.shape[0]),
@@ -508,6 +518,7 @@ def _edge_operands(p: _Plan, src, dst, valid, src_ids, dst_ids, e_spec):
     return seg_p, pad_e, operands, [e_spec] * len(operands)
 
 
+@obs.scope(obs.PLANE_KERNEL)
 def gather_emit_combine(emit_fn, monoid: str, src, dst, vprops, eprops,
                         active, num_vertices: int, *, valid=None,
                         src_ids=None, dst_ids=None, prefetch=None,
@@ -585,20 +596,24 @@ def gather_emit_combine(emit_fn, monoid: str, src, dst, vprops, eprops,
                              (blk(win, code, t, j),)),
                 pl.BlockSpec((slab,), lambda t, code, win, *_, j=j:
                              (blk(win, code, t, j) + 1,))]
-        scalar_ops.append(jnp.pad(
-            win_idx.astype(jnp.int32),
-            (0, p.n_e * sub_blocks - int(win_idx.shape[0]))))
-        for leaf in vertex_leaves:
-            leaf = jnp.pad(leaf, (0, VW_pad - leaf.shape[0]))
-            operands += [leaf] * len(v_specs)
-            in_specs += v_specs
+        with obs.scope(obs.PLANE_OPERANDS):  # the kernel gathers
+            scalar_ops.append(jnp.pad(
+                win_idx.astype(jnp.int32),
+                (0, p.n_e * sub_blocks - int(win_idx.shape[0]))))
+            for leaf in vertex_leaves:
+                leaf = jnp.pad(leaf, (0, VW_pad - leaf.shape[0]))
+                operands += [leaf] * len(v_specs)
+                in_specs += v_specs
     else:
         # resident: XLA gathers the src rows into edge order once
         src_c = src.astype(jnp.int32)
-        for leaf in vertex_leaves:
-            operands.append(pad_e(jnp.take(leaf, src_c, axis=0), 0))
-            in_specs.append(e_spec)
-    operands += [pad_e(l.astype(_carrier(l.dtype)), 0) for l in ep_leaves]
+        with obs.scope(obs.PLANE_GATHER):
+            for leaf in vertex_leaves:
+                operands.append(pad_e(jnp.take(leaf, src_c, axis=0), 0))
+                in_specs.append(e_spec)
+    with obs.scope(obs.PLANE_OPERANDS):
+        operands += [pad_e(l.astype(_carrier(l.dtype)), 0)
+                     for l in ep_leaves]
     in_specs += [e_spec] * len(ep_leaves)
     if block_skip:
         scalar_ops.append(_block_active(
@@ -817,6 +832,7 @@ def _packed_kernel(*refs, emit_fn, pack, vp_def, n_ep, ep_def, ep_vector,
         hm_out[...] = hm_acc[0]
 
 
+@obs.scope(obs.PLANE_KERNEL)
 def gather_emit_combine_packed(emit_fn, monoids, src, dst, vprops, eprops,
                                active, num_vertices: int, *, valid=None,
                                src_ids=None, dst_ids=None,
@@ -867,23 +883,25 @@ def gather_emit_combine_packed(emit_fn, monoids, src, dst, vprops, eprops,
     mask = (1 << p.shift) - 1
     slab_spec = lambda w: pl.BlockSpec(
         (w, p.be), lambda t, code, *_: (0, code[t] & mask))
-    src_c = src.astype(jnp.int32)
     has_act = active is not None
-    if has_act:
-        operands.append(pad_e(jnp.take(
-            jnp.asarray(active).astype(jnp.int32), src_c, axis=0), 0))
-        in_specs.append(e_spec)
-    # XLA gathers each lane-major vertex slab into edge order once
-    for g in pack.vp_groups:
-        slab = _pack_rows(vp_leaves, g, 0)
-        operands.append(pad_cols(jnp.take(
-            slab.astype(_carrier(slab.dtype)), src_c, axis=1)))
-        in_specs.append(slab_spec(g.width))
+    with obs.scope(obs.PLANE_GATHER):
+        src_c = src.astype(jnp.int32)
+        if has_act:
+            operands.append(pad_e(jnp.take(
+                jnp.asarray(active).astype(jnp.int32), src_c, axis=0), 0))
+            in_specs.append(e_spec)
+        # XLA gathers each lane-major vertex slab into edge order once
+        for g in pack.vp_groups:
+            slab = _pack_rows(vp_leaves, g, 0)
+            operands.append(pad_cols(jnp.take(
+                slab.astype(_carrier(slab.dtype)), src_c, axis=1)))
+            in_specs.append(slab_spec(g.width))
     ep_vector = tuple(l.ndim > 1 for l in ep_leaves)
-    for l, vec in zip(ep_leaves, ep_vector):
-        l = l.astype(_carrier(l.dtype))
-        operands.append(pad_cols(l.T) if vec else pad_e(l, 0))
-        in_specs.append(slab_spec(l.shape[1]) if vec else e_spec)
+    with obs.scope(obs.PLANE_OPERANDS):
+        for l, vec in zip(ep_leaves, ep_vector):
+            l = l.astype(_carrier(l.dtype))
+            operands.append(pad_cols(l.T) if vec else pad_e(l, 0))
+            in_specs.append(slab_spec(l.shape[1]) if vec else e_spec)
     if block_skip:
         scalar_ops.append(_block_active(
             jnp.ones((V,), bool) if active is None else active, src, valid,

@@ -336,13 +336,13 @@ class ServingSession:
             free = hit or lo > 0
             label = f"{op} runner (q_bucket={cw}, warm={warm is not None})"
             if warm is None:
-                wrapped, it, na = self._invoke(
+                wrapped, it, na, _ = self._invoke(
                     label, free, lambda: entry["runner"](gdev,
                                                          bp.lane_values))
             else:
                 wv, wa = warm
                 wv_c = jax.tree.map(lambda a: a[..., lo:lo + cw], wv)
-                wrapped, it, na = self._invoke(
+                wrapped, it, na, _ = self._invoke(
                     label, free,
                     lambda: entry["runner"](gdev, bp.lane_values, wv_c, wa))
             outs.append(wrapped["p"])
@@ -384,11 +384,11 @@ class ServingSession:
         gdev = self._gdev()
         label = f"{op} runner (global, warm={warm is not None})"
         if warm is None:
-            rec, it, na = self._invoke(label, hit,
-                                       lambda: entry["runner"](gdev, ()))
+            rec, it, na, _ = self._invoke(
+                label, hit, lambda: entry["runner"](gdev, ()))
         else:
             wv, wa = warm
-            rec, it, na = self._invoke(
+            rec, it, na, _ = self._invoke(
                 label, hit, lambda: entry["runner"](gdev, (), wv, wa))
         info = {**self._base_info(), "iterations": int(it),
                 "active_at_end": int(na), "converged": int(na) == 0}
