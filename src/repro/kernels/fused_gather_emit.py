@@ -22,10 +22,13 @@ streams each edge block about once (the product grid streamed it n_vb
 times). Steps past the table's live length repeat the last pair and do
 nothing.
 
-Where the src rows come from:
-  * resident (default): XLA gathers the src rows of every vertex-property
-    leaf (and the frontier flag) into edge-ordered [E] operands, which the
-    kernel streams with the edges. VMEM holds O(block) state at any V.
+Where the src rows come from. A pass gathers only the LIVE vertex
+columns: the frontier flag and the vertex-property leaves the emit reads
+(`live_vertex_leaves`); a dead leaf reaches emit as zeros.
+  * resident (default): XLA gathers the live columns' src rows into edge
+    order, which the kernel streams with the edges. The columns are the
+    rows of one lane-major [W, V] int32 table gathered once (a TPU gather
+    costs per index, not per byte). VMEM holds O(block) state at any V.
   * scalar-prefetch (`prefetch=(block_idx, window, block_e)`): a window
     table (`core/graph_device.py::compute_prefetch_windows`) names, per
     edge block, a pair of adjacent src slabs that cover its src span; the
@@ -235,16 +238,52 @@ def _block_active(active, src, valid, pad_e, n_e: int, be: int):
 # Schema checks
 # ---------------------------------------------------------------------------
 
-def _emit_schema(emit_fn, num_edges: int, vprops, eprops):
-    """Abstract-trace the vmapped emit: (is_emit_sds, msg_sds pytree)."""
+def _emit_trace(emit_fn, num_edges: int, vprops, eprops):
+    """Abstract-trace the vmapped emit once: (closed jaxpr, (is_emit_sds,
+    msg_sds pytree))."""
     E = int(num_edges)
-    return jax.eval_shape(
-        jax.vmap(emit_fn), jax.ShapeDtypeStruct((E,), jnp.int32),
+    return jax.make_jaxpr(jax.vmap(emit_fn), return_shape=True)(
+        jax.ShapeDtypeStruct((E,), jnp.int32),
         jax.ShapeDtypeStruct((E,), jnp.int32),
         jax.tree.map(lambda a: jax.ShapeDtypeStruct((E,) + a.shape[1:],
                                                     a.dtype), vprops),
         jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
                      eprops))
+
+
+def _emit_schema(emit_fn, num_edges: int, vprops, eprops):
+    """(is_emit_sds, msg_sds pytree) of the vmapped emit."""
+    return _emit_trace(emit_fn, num_edges, vprops, eprops)[1]
+
+
+def _live_leaves(closed, n_vp: int) -> Tuple[bool, ...]:
+    """Per vertex-property leaf of the traced emit (`_emit_trace`): can
+    the emit's outputs depend on it? See `live_vertex_leaves`."""
+    jaxpr, every = closed.jaxpr, (True,) * n_vp
+    if jaxpr.effects:
+        return every
+    try:  # JAX's own DCE pass, which is not public API
+        from jax._src.interpreters import partial_eval as pe
+        used = pe.dce_jaxpr(jaxpr, [True] * len(jaxpr.outvars))[1]
+        return tuple(bool(u) for u in used[2:2 + n_vp]) \
+            if len(used) == len(jaxpr.invars) else every
+    except Exception:  # noqa: BLE001 -- any failure keeps every leaf
+        return every
+
+
+def live_vertex_leaves(emit_fn, num_edges: int, vprops, eprops
+                       ) -> Tuple[bool, ...]:
+    """Per flattened `vprops` leaf: can the emit's outputs depend on it?
+
+    A backward liveness (dead-code) pass over the jaxpr of the vmapped
+    emit; nested jits, conds and loops are looked into by JAX's own DCE
+    rules, and a primitive without one keeps all its inputs. A dead leaf
+    need not be gathered: emit may be handed anything in its place.
+    Conservative: an emit with effects, or one the pass fails on for any
+    reason, reads every leaf. An emit that fails to trace raises, as it
+    would on any path."""
+    return _live_leaves(_emit_trace(emit_fn, num_edges, vprops, eprops)[0],
+                        len(jax.tree.leaves(vprops)))
 
 
 def _schema_ok(emit_sds, num_edges, num_vertices, vprops, eprops,
@@ -386,9 +425,9 @@ def _fold_row(acc, rows, vl, onehot, hit, monoid: str, ident):
 # Scalar-record kernel
 # ---------------------------------------------------------------------------
 
-def _kernel(*refs, emit_fn, monoid, n_vp, n_ep, n_msg, vp_def, ep_def,
-            vp_dtypes, ep_dtypes, idents, p, num_edges, has_act, has_valid,
-            has_ids, window, slab, sub_blocks, blockskip):
+def _kernel(*refs, emit_fn, monoid, vp_live, n_ep, n_msg, vp_def, ep_def,
+            vp_dtypes, ep_dtypes, col_dtypes, idents, p, num_edges, has_act,
+            has_valid, has_ids, window, slab, sub_blocks, blockskip):
     code_ref, refs = refs[0], refs[1:]
     if window:
         win_ref, refs = refs[0], refs[1:]
@@ -403,13 +442,16 @@ def _kernel(*refs, emit_fn, monoid, n_vp, n_ep, n_msg, vp_def, ep_def,
     if has_ids:
         sid_ref, did_ref = refs[k], refs[k + 1]
         k += 2
-    # window mode: a (lo, hi) slab pair per leaf per window-table block
-    n_slab = 2 * sub_blocks if window else 1
-    act_refs = refs[k:k + n_slab] if has_act else ()
-    k += len(act_refs)
-    vp_refs = refs[k:k + n_slab * n_vp]
-    ep_refs = refs[k + n_slab * n_vp:k + n_slab * n_vp + n_ep]
-    k += n_slab * n_vp + n_ep
+    # the gathered columns: the frontier flag (when has_act), then the
+    # live vertex-property leaves. Window mode: a (lo, hi) slab pair per
+    # column per window-table block; resident: one [W, BE] int32 block
+    # holding every column as a row
+    n_slab = 2 * sub_blocks
+    n_col_refs = (n_slab * len(col_dtypes) if window
+                  else min(len(col_dtypes), 1))
+    col_refs = refs[k:k + n_col_refs]
+    ep_refs = refs[k + n_col_refs:k + n_col_refs + n_ep]
+    k += n_col_refs + n_ep
     out_refs = refs[k:k + n_msg]
     hm_out = refs[k + n_msg]
     acc_refs = refs[k + n_msg + 1:k + 2 * n_msg + 1]
@@ -457,14 +499,23 @@ def _kernel(*refs, emit_fn, monoid, n_vp, n_ep, n_msg, vp_def, ep_def,
                     out = g if out is None else jnp.where(part == j, g, out)
                 return out
 
-            sp_leaves = [gather(vp_refs[i * n_slab:(i + 1) * n_slab])
-                         for i in range(n_vp)]
-            act = gather(act_refs) > 0 if has_act else None
-            act = in_win if act is None else act & in_win
+            cols = [gather(col_refs[i * n_slab:(i + 1) * n_slab])
+                    for i in range(len(col_dtypes))]
+        elif col_refs:
+            table = col_refs[0][...]
+            cols = [jax.lax.bitcast_convert_type(table[i], dt)
+                    for i, dt in enumerate(col_dtypes)]
         else:
-            sp_leaves = [r[...] for r in vp_refs]
-            act = act_refs[0][...] > 0 if has_act else None
-        sp_leaves = [x.astype(dt) for x, dt in zip(sp_leaves, vp_dtypes)]
+            cols = []
+        act = cols[0] > 0 if has_act else None
+        if window:
+            act = in_win if act is None else act & in_win
+        # a dead leaf (one emit never reads) was not gathered: emit gets
+        # zeros in its place, which cannot change what emit returns
+        vals = iter(cols[int(has_act):])
+        sp_leaves = [(next(vals) if used else
+                      jnp.zeros((p.be,), _carrier(dt))).astype(dt)
+                     for used, dt in zip(vp_live, vp_dtypes)]
         ep_leaves = [r[...].astype(dt) for r, dt in zip(ep_refs, ep_dtypes)]
         msg_leaves, valid = _emit_valid(
             emit_fn, vp_def, ep_def, sp_leaves, ep_leaves,
@@ -546,8 +597,8 @@ def gather_emit_combine(emit_fn, monoid: str, src, dst, vprops, eprops,
     vp_leaves, vp_def = jax.tree.flatten(vprops)
     ep_leaves, ep_def = jax.tree.flatten(eprops)
 
-    # message schema from an abstract trace of the vmapped emit
-    emit_sds = _emit_schema(emit_fn, E, vprops, eprops)
+    # message schema and live leaves from one abstract trace of the emit
+    closed, emit_sds = _emit_trace(emit_fn, E, vprops, eprops)
     msg_sds = jax.tree.leaves(emit_sds[1])
     if not _schema_ok(emit_sds, E, V, vprops, eprops):
         raise ValueError("fused kernel needs scalar record leaves")
@@ -576,8 +627,14 @@ def gather_emit_combine(emit_fn, monoid: str, src, dst, vprops, eprops,
         p, src, dst, valid, src_ids, dst_ids, e_spec)
     scalar_ops = [_visit_table(seg_p, p)]
     has_act = active is not None
+    # the vertex columns the pass gathers: the frontier flag and the leaves
+    # emit reads (dead ones are neither gathered nor streamed)
+    live = _live_leaves(closed, len(vp_leaves))
     vertex_leaves = [l.astype(_carrier(l.dtype)) for l in
-                     ([jnp.asarray(active)] if has_act else []) + vp_leaves]
+                     ([jnp.asarray(active)] if has_act else [])
+                     + [l for l, used in zip(vp_leaves, live) if used]]
+    obs.add(obs.GATHER_COLUMNS, int(has_act) + len(vp_leaves))
+    obs.add(obs.GATHERED_COLUMNS, len(vertex_leaves))
     if window:
         # window-table block j of edge block eb DMAs the slab PAIR that
         # covers [q·W, (q+2)·W), q = win[eb·k + j], in slabs of
@@ -604,13 +661,22 @@ def gather_emit_combine(emit_fn, monoid: str, src, dst, vprops, eprops,
                 leaf = jnp.pad(leaf, (0, VW_pad - leaf.shape[0]))
                 operands += [leaf] * len(v_specs)
                 in_specs += v_specs
-    else:
-        # resident: XLA gathers the src rows into edge order once
-        src_c = src.astype(jnp.int32)
+    elif vertex_leaves:
+        # resident: ONE XLA gather puts the src rows of every live column
+        # into edge order. The columns, bitcast to int32, are the rows of a
+        # lane-major [W, V] table, W = the live columns (no padding rows:
+        # W = 1 is a 1-D gather), gathered along its lanes; the kernel
+        # streams [W, BE] blocks. A TPU gather costs per index, not
+        # per byte, so one index stream costs about what one column does
+        mask = (1 << p.shift) - 1
         with obs.scope(obs.PLANE_GATHER):
-            for leaf in vertex_leaves:
-                operands.append(pad_e(jnp.take(leaf, src_c, axis=0), 0))
-                in_specs.append(e_spec)
+            table = jnp.stack([jax.lax.bitcast_convert_type(l, jnp.int32)
+                               for l in vertex_leaves])
+            rows = jnp.take(table, src.astype(jnp.int32), axis=1)
+            operands.append(jnp.pad(rows, ((0, 0), (0, p.E_pad - E))))
+        in_specs.append(pl.BlockSpec(
+            (len(vertex_leaves), p.be),
+            lambda t, code, *_: (0, code[t] & mask)))
     with obs.scope(obs.PLANE_OPERANDS):
         operands += [pad_e(l.astype(_carrier(l.dtype)), 0)
                      for l in ep_leaves]
@@ -621,11 +687,12 @@ def gather_emit_combine(emit_fn, monoid: str, src, dst, vprops, eprops,
             pad_e, p.n_e, p.be))
 
     body = functools.partial(
-        _kernel, emit_fn=emit_fn, monoid=monoid, n_vp=len(vp_leaves),
+        _kernel, emit_fn=emit_fn, monoid=monoid, vp_live=live,
         n_ep=len(ep_leaves), n_msg=len(msg_sds), vp_def=vp_def,
         ep_def=ep_def, vp_dtypes=tuple(l.dtype for l in vp_leaves),
-        ep_dtypes=tuple(l.dtype for l in ep_leaves), idents=idents, p=p,
-        num_edges=E, has_act=has_act,
+        ep_dtypes=tuple(l.dtype for l in ep_leaves),
+        col_dtypes=tuple(l.dtype for l in vertex_leaves), idents=idents,
+        p=p, num_edges=E, has_act=has_act,
         has_valid=valid is not None,
         has_ids=src_ids is not None or dst_ids is not None, window=window,
         slab=slab, sub_blocks=sub_blocks, blockskip=bool(block_skip))

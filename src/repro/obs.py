@@ -50,6 +50,11 @@ RUN = "unigps.run"
 # -- counters
 ACTIVE_EDGES = "plane.active_edges"  # slots whose source was on the frontier
 EDGE_SLOTS = "plane.edge_slots"      # slots streamed: stored x supersteps
+# per traced fused pass (trace time, so a jit cache hit adds nothing): the
+# vertex columns it could gather into edge order (frontier flag plus every
+# vertex-property leaf), and those it did gather (the ones emit reads)
+GATHER_COLUMNS = "plane.gather_columns"
+GATHERED_COLUMNS = "plane.gathered_columns"
 
 _job = contextvars.ContextVar("unigps_job", default=None)
 _job_ids = itertools.count(1)

@@ -11,6 +11,7 @@ only the worker that runs this file loads the TPU compiler, and the tests
 skip where no topology can be described.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -175,6 +176,48 @@ def test_visit_table_fits_scalar_memory(one_chip, mosaic):
              _sds((big_v,), jnp.float32, one_chip),
              _sds((big_e,), jnp.float32, one_chip),
              _sds((big_v,), jnp.bool_, one_chip))
+
+
+def _sssp_pass(one_chip, v, e):
+    """SSSP's resident pass (the emit reads the frontier flag and
+    `distance`, not `vid`) compiled for one chip at V = v, E = e."""
+    from repro.core import operators
+    prog = operators.SSSPProgram(0)
+
+    def plane(src, dst, vprops, eprops, active):
+        return fge.gather_emit_combine(prog.emit_message, "min", src, dst,
+                                       vprops, eprops, active, v)
+
+    return _compile(
+        plane, _sds((e,), jnp.int32, one_chip),
+        _sds((e,), jnp.int32, one_chip),
+        {"distance": _sds((v,), jnp.float32, one_chip),
+         "vid": _sds((v,), jnp.int32, one_chip)},
+        {"weight": _sds((e,), jnp.float32, one_chip)},
+        _sds((v,), jnp.bool_, one_chip))
+
+
+def test_sssp_resident_pass_gathers_once(one_chip, mosaic):
+    """At graph500-16 shapes the compiled pass holds one gather into edge
+    order: the two live columns as the rows of one [2, E] int32 table,
+    laid out densely."""
+    text = _sssp_pass(one_chip, V, E).as_text()
+    into_edges = re.findall(rf"= (\S*\b{E}\b\S*) gather\(", text)
+    assert [shape.split(":")[0] for shape in into_edges] \
+        == [f"s32[2,{E}]{{1,0"]
+    assert f"s32[2,{E}]{{1,0:T(2,128)}} gather(" in text
+
+
+@pytest.mark.parametrize("scale,edges", [(21, 67106064), (22, 134213632)])
+def test_sssp_resident_pass_memory(one_chip, mosaic, scale, edges):
+    """At graph500-21 and -22 shapes the pass's temporaries hold one
+    E-sized int32 word per live column (2) and per padded edge operand
+    (dst, src, weight): the gathered rows are no wider than the columns
+    emit reads, and no second copy of them is made to pad them."""
+    e_pad = fge._plan(edges, 1 << scale, fge.TILE_1D, fge.TILE_1D).E_pad
+    temp = _sssp_pass(one_chip, 1 << scale, edges).memory_analysis() \
+        .temp_size_in_bytes
+    assert temp <= (2 + 3) * 4 * e_pad + (1 << 20)
 
 
 def test_visit_table_covers_every_overlap():
